@@ -46,6 +46,8 @@ def main(argv=None) -> None:
                          "a Chrome trace-event JSON")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (analysis_bench, fig1_summary, kernels_bench,
                             pdgrass_perf, replay_bench, solver_bench,
                             spectral_bench, table2_quality, table3_jbp,

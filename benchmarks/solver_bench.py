@@ -126,9 +126,8 @@ def sharded_solve_row(name, g, B, pd_cfg, ref, repeat=1):
     within +-2.
     """
     import jax
-    from repro.launch.mesh import compat_make_mesh
 
-    mesh = compat_make_mesh((jax.device_count(),), ("data",))
+    mesh = jax.make_mesh((jax.device_count(),), ("data",))
     svc = SolverService(pipeline=pd_cfg, mesh=mesh)
     handle = svc.register(g)
     t0 = time.perf_counter()
@@ -261,6 +260,8 @@ def main(argv=None):
                          "ui.perfetto.dev)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace:
         from repro.obs import enable_tracing
         enable_tracing()

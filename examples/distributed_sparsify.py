@@ -22,7 +22,6 @@ import jax  # noqa: E402
 
 from repro.core import barabasi_albert  # noqa: E402
 from repro.core.distributed import partition_subtasks  # noqa: E402
-from repro.launch.mesh import compat_make_mesh  # noqa: E402
 from repro.pipeline import Pipeline, pdgrass_config  # noqa: E402
 from repro.solver import SolverService  # noqa: E402
 
@@ -39,7 +38,7 @@ def main():
     serial_pipe = Pipeline(pdgrass_config(alpha=0.05, chunk=512,
                                           engine="serial"))
     prep = dist_pipe.prepare(g)   # shared steps 1-3 for both engines
-    mesh = compat_make_mesh((jax.device_count(),), ("data",))
+    mesh = jax.make_mesh((jax.device_count(),), ("data",))
     shard_of, giants, load = partition_subtasks(
         prep.subtask_sizes, jax.device_count())
     print(f"subtasks={prep.n_subtasks} giants={len(giants)} "

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.core import mesh2d, pdgrass
 from repro.core.pcg import pcg_host
+from repro.launch.compile_cache import enable_compile_cache
 from repro.pipeline import fegrass_config, pdgrass_config
 from repro.solver import SolveRequest, SolverService
 
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8,
                     help="number of right-hand sides per request")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.scale == "small":
         g = mesh2d(60, 60, seed=0)

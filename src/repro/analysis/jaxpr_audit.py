@@ -9,8 +9,8 @@ between two shapes of the same RHS bucket).
 Four rules over each entry of :data:`repro.analysis.registry.HOT_ENTRIES`:
 
 ``jaxpr-host-callback``
-    any callback-family primitive (``debug_callback`` from
-    ``jax.debug.print``, ``pure_callback``, ``io_callback``,
+    any callback-family primitive (``debug_print`` from
+    ``jax.debug.print``, ``debug_callback``, ``pure_callback``, ``io_callback``,
     ``infeed``/``outfeed``) anywhere in the traced closure — each one is
     a device->host round trip per invocation.
 
@@ -20,9 +20,9 @@ Four rules over each entry of :data:`repro.analysis.registry.HOT_ENTRIES`:
 
 ``jaxpr-f64-promotion``
     ``convert_element_type`` to float64, or any f64-dtyped intermediate,
-    inside a declared-f32 entry.  Traced under ``jax.experimental.
-    enable_x64``: with x64 disabled jax silently *downgrades* f64
-    requests, which would mask exactly the promotions we hunt.
+    inside a declared-f32 entry.  Traced under ``jax.enable_x64``: with
+    x64 disabled jax silently *downgrades* f64 requests, which would mask
+    exactly the promotions we hunt.
 
 ``jaxpr-recompile-hazard``
     the entry traced at two shapes in the same RHS pow2 bucket (k=5 and
@@ -42,26 +42,24 @@ from repro.analysis.findings import Finding
 from repro.analysis.registry import HOT_ENTRIES, HotEntry
 
 _CALLBACK_PRIMS = {
-    "debug_callback", "pure_callback", "io_callback", "callback",
+    "debug_print", "debug_callback", "pure_callback", "io_callback", "callback",
     "host_callback_call", "outside_call", "infeed", "outfeed",
 }
 
 # primitives whose params hold sub-jaxprs we must recurse into; everything
 # is discovered generically from eqn.params, these are only for while-body
 # special-casing
-_WHILE_PRIM = "while"
+_WHILE_PRIM = "while"   # compared case-insensitively: jax names it "While"
 
 
 def _sub_jaxprs(params: dict):
     """Yield every (Closed)Jaxpr reachable from an eqn's params."""
-    import jax.core as jcore
-    closed = getattr(jcore, "ClosedJaxpr", None)
-    open_ = getattr(jcore, "Jaxpr", None)
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def walk(obj):
-        if closed is not None and isinstance(obj, closed):
+        if isinstance(obj, ClosedJaxpr):
             yield obj.jaxpr
-        elif open_ is not None and isinstance(obj, open_):
+        elif isinstance(obj, Jaxpr):
             yield obj
         elif isinstance(obj, (list, tuple)):
             for item in obj:
@@ -98,7 +96,7 @@ def _walk(jaxpr, in_while: bool):
     """Yield ``(eqn, in_while)`` over the jaxpr and all sub-jaxprs."""
     for eqn in jaxpr.eqns:
         yield eqn, in_while
-        inner_while = in_while or eqn.primitive.name == _WHILE_PRIM
+        inner_while = in_while or eqn.primitive.name.lower() == _WHILE_PRIM
         for sub in _sub_jaxprs(eqn.params):
             yield from _walk(sub, inner_while)
 
@@ -115,11 +113,10 @@ def _prim_structure(jaxpr) -> Tuple[str, ...]:
 
 def _trace(fn, args, static_argnums: Tuple[int, ...]):
     import jax
-    from jax.experimental import enable_x64
     # x64 ON while tracing: with x64 off, jax silently downgrades f64 and
     # the promotion rule would never fire.  Entries are built f32, so a
     # clean path stays f32 under either flag.
-    with enable_x64(True):
+    with jax.enable_x64(True):
         return jax.make_jaxpr(fn, static_argnums=static_argnums)(*args)
 
 

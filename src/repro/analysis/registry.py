@@ -91,10 +91,9 @@ def _build_vcycle(impl: str):
 
 
 def _build_sharded_solver():
-    from repro.launch.mesh import compat_make_mesh
     from repro.solver.sharded import make_sharded_solver
     g, idx, val, hier = _shared_artifacts()
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     solve = make_sharded_solver(idx, val, hier, precond="hierarchy",
                                 mesh=mesh, matvec_impl="ref")
     return solve, (_rhs(g.n, 5),), (_rhs(g.n, 7),), ()

@@ -36,7 +36,7 @@ Three rules:
 Traced scopes are discovered statically, best-effort by construction:
 functions decorated with ``jax.jit`` (including ``partial(jax.jit, ...)``,
 honoring ``static_argnums``/``static_argnames``), functions passed by name
-to ``jax.jit`` / ``shard_map`` / ``shard_map_compat`` / ``lax.while_loop``
+to ``jax.jit`` / ``shard_map`` / ``lax.while_loop``
 / ``lax.fori_loop`` / ``lax.scan`` / ``lax.cond``, plus module-local
 functions those call (one call-graph closure, by simple name).  Nested
 defs inside a traced scope are scanned with their *own* parameters treated
@@ -83,8 +83,6 @@ _TRACING_CALLEES = {
     "scan": (0,),
     "cond": (1, 2),
     "shard_map": (0,),
-    "shard_map_compat": (0,),
-    "_shard_map": (0,),
 }
 
 

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.graph_ops import shard_map_compat as _shard_map
 from repro.obs import get_metrics, get_tracer
 
 from repro.core import recovery as rec_mod
@@ -164,7 +163,7 @@ def recover_outer(sharded: ShardedProblem, mesh, axis: str = "data",
             stop_at_target=False, chunk=chunk)
         return status[None], stats.rounds[None]
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis)))
@@ -188,9 +187,6 @@ def _inner_round_engine(sig_u, sig_v, beta, seg, axis: str, n_sh: int,
     :func:`recover_inner` wrapper (which reads ``mesh.shape[axis]``).  It
     must be static: the engine builds ``jnp.arange(n_sh)`` and reshapes
     gathered blocks by it, neither of which traces from a dynamic value.
-    (A ``jax.lax.psum(1, axis)`` fallback — used before jax grew
-    ``jax.lax.axis_size`` — yields a *traced* value on those builds and
-    broke exactly there.)
     """
     m_loc = seg.shape[0]
     c1 = sig_u.shape[1]
@@ -285,7 +281,7 @@ def recover_inner(sig_u, sig_v, beta, seg, mesh, axis: str = "data",
 
     The wrapper knows the mesh, so the shard count goes in as a static
     Python int — the engine never derives it from collectives."""
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_inner_round_engine, axis=axis,
                           n_sh=int(mesh.shape[axis]),
                           block_size=block_size, chunk=chunk),

@@ -38,8 +38,6 @@ is a two-phase (local combine, ``all_gather``, final merge) contraction.
 All three are *bit-identical* to the single-device primitives on the same
 input — the strict total order survives the collectives — which is what
 lets the sharded hierarchy build serve as a drop-in for the device one.
-:func:`shard_map_compat` is the version-portable ``shard_map`` entry point
-every mesh consumer in the repo shares.
 """
 from __future__ import annotations
 
@@ -47,17 +45,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.5 exposes shard_map at the top level
-    shard_map_compat = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map_compat(f, **kw):
-        # the experimental version can't prove replication across
-        # while_loop bodies; callers are replication-safe by construction.
-        return _exp_shard_map(f, check_rep=False, **kw)
-
 
 def segment_argmax(values: jnp.ndarray, segment_ids: jnp.ndarray,
                    num_segments: int, *,

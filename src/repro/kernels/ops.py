@@ -1,10 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
 ``interpret=None`` (the default everywhere) resolves automatically via
-:func:`resolve_interpret`: an explicit bool wins, else the
-``REPRO_KERNEL_INTERPRET`` environment variable (``"0"`` = compiled), else
-the kernels compile through Mosaic only when ``jax.default_backend()`` is
-TPU and interpret everywhere else (CPU containers, CI).
+:func:`resolve_interpret`: an explicit bool wins, else the kernels compile
+through Mosaic when ``jax.default_backend()`` is TPU and interpret
+everywhere else (CPU containers, CI).
 """
 from __future__ import annotations
 
@@ -20,7 +19,6 @@ from repro.kernels.vcycle_fused import (  # noqa: F401
 
 def similarity_mark(csu, csv, cbeta, cseg, esu, esv, eseg,
                     tile_m: int = 512, interpret: bool | None = None):
-    interpret = resolve_interpret(interpret)
     m = esu.shape[0]
     if m % tile_m != 0:  # pad to tile multiple with inert rows
         pad = tile_m - m % tile_m
@@ -35,15 +33,13 @@ def similarity_mark(csu, csv, cbeta, cseg, esu, esv, eseg,
 def spmv(idx, val, x, tile_n: int = 256, interpret: bool | None = None):
     """Single-column ELL spmv; non-tile-multiple row counts pad inside
     the kernel wrapper."""
-    return _spmv_ell(idx, val, x, tile_n=tile_n,
-                     interpret=resolve_interpret(interpret))
+    return _spmv_ell(idx, val, x, tile_n=tile_n, interpret=interpret)
 
 
 def spmv_batched(idx, val, x, tile_n: int = 256,
                  interpret: bool | None = None):
     """Batched-RHS ELL spmv: the whole ``[n, k]`` block in one kernel."""
-    return _spmv_ell_batched(idx, val, x, tile_n=tile_n,
-                             interpret=resolve_interpret(interpret))
+    return _spmv_ell_batched(idx, val, x, tile_n=tile_n, interpret=interpret)
 
 
 similarity_mark_ref = _ref.similarity_mark_ref

@@ -25,10 +25,13 @@ Block layout per grid step (c1 = 9, int32):
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.vcycle_fused import resolve_interpret
 
 
 def _sim_kernel(csu_ref, csv_ref, cbeta_ref, cseg_ref,
@@ -70,7 +73,7 @@ def _sim_kernel(csu_ref, csv_ref, cbeta_ref, cseg_ref,
 @functools.partial(jax.jit,
                    static_argnames=("tile_m", "interpret"))
 def similarity_mark(csu, csv, cbeta, cseg, esu, esv, eseg,
-                    *, tile_m: int = 512, interpret: bool = True):
+                    *, tile_m: int = 512, interpret: Optional[bool] = None):
     """kill[j] = any recovered candidate k (same subtask) marks edge j.
 
     Args:
@@ -80,7 +83,11 @@ def similarity_mark(csu, csv, cbeta, cseg, esu, esv, eseg,
       esu/esv:   [m, c1] int32 edge slab signatures; m % tile_m == 0.
       eseg:      [m] int32 (-1 for padding rows).
     Returns: [m] bool.
+
+    ``interpret=None`` resolves through
+    :func:`repro.kernels.vcycle_fused.resolve_interpret`.
     """
+    interpret = resolve_interpret(interpret)
     m, c1 = esu.shape
     assert m % tile_m == 0, (m, tile_m)
     grid = (m // tile_m,)

@@ -15,10 +15,13 @@ degree) dimension is unrolled.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.vcycle_fused import resolve_interpret
 
 
 def _spmv_kernel(idx_ref, val_ref, x_ref, out_ref):
@@ -32,13 +35,16 @@ def _spmv_kernel(idx_ref, val_ref, x_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
-def spmv_ell(idx, val, x, *, tile_n: int = 256, interpret: bool = True):
+def spmv_ell(idx, val, x, *, tile_n: int = 256,
+             interpret: Optional[bool] = None):
     """y[i] = sum_l val[i, l] * x[idx[i, l]].  Rows padded with val = 0.
 
     Row counts that are not a multiple of ``tile_n`` are padded up to the
     tile boundary with zero-valued ELL entries (which gather ``x[0]`` and
     contribute nothing) and sliced back — arbitrary graph sizes never
-    crash the kernel."""
+    crash the kernel.  ``interpret=None`` resolves through
+    :func:`repro.kernels.vcycle_fused.resolve_interpret`."""
+    interpret = resolve_interpret(interpret)
     n, L = idx.shape
     pad = (-n) % tile_n
     if pad:

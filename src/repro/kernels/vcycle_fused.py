@@ -40,11 +40,15 @@ one and PCG iteration counts match exactly (asserted in
 
 ``interpret=None`` everywhere means "resolve automatically" — see
 :func:`resolve_interpret`.
+
+Mosaic refuses all three kernels today (the in-kernel ``x[idx]`` gather
+has no TPU lowering; ``tests/test_tpu_compile.py`` records each refusal),
+so the solve plane's declared default is the jnp reference path and these
+kernels run only where a caller selects ``matvec_impl="fused"``.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Callable, Optional
 
 import jax
@@ -55,17 +59,12 @@ from jax.experimental import pallas as pl
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     """Resolve the Pallas ``interpret`` knob.
 
-    Priority: an explicit ``True``/``False`` wins; else the
-    ``REPRO_KERNEL_INTERPRET`` environment variable (``"0"`` = compiled,
-    anything else = interpret); else auto-select from
-    ``jax.default_backend()`` — compiled on TPU (the kernels lower
-    through Mosaic), interpret everywhere else (CPU containers, CI).
+    An explicit ``True``/``False`` wins.  ``None`` compiles through Mosaic
+    on a TPU and interprets on every other backend, so a kernel runs in
+    interpret mode on a TPU only when its caller asks for it by name.
     """
     if interpret is not None:
         return bool(interpret)
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env != "0"
     return jax.default_backend() != "tpu"
 
 
@@ -102,11 +101,24 @@ def cheby_recurrence(matvec: Callable, inv_d, r, z, *, degree: int,
     return z
 
 
+def ell_contract(idx, val, x):
+    """``y[i, j] = sum_l val[i, l] * x[idx[i, l], j]`` for ``x [nx, k]``.
+
+    The one definition of the ELL contraction: the jnp reference matvec,
+    the sharded plane's per-shard matvec and the fused kernels' bodies all
+    call it.  The gather runs on ``x.T`` so the row axis stays minor: the
+    ``[n, L, k]`` intermediate of a plain ``x[idx]`` has small trailing
+    axes that a TPU pads to its 128 lanes, which at n = 2^20, L = 8, k = 8
+    is 4.5 GiB of temporaries against 256 MiB this way (the TPU compiler's
+    memory analysis for a v5e)."""
+    return jnp.einsum("nl,kln->nk", val, x.T[:, idx.T])
+
+
 def _ell_matvec(idx, val):
     """In-kernel ELL contraction ``x [nx, k] -> [n, k]`` over VMEM-resident
-    slabs — the same einsum expression as the jnp reference path."""
+    slabs — :func:`ell_contract`, as in the jnp reference path."""
     def mv(x):
-        return jnp.einsum("nl,nlk->nk", val, x[idx])
+        return ell_contract(idx, val, x)
 
     return mv
 

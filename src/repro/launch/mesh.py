@@ -16,21 +16,17 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: the ``axis_types`` kwarg (and
-    ``jax.sharding.AxisType``) only exist on newer jax; older versions
-    default every axis to auto sharding, which is what we want anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis in auto sharding mode, which the
+    sharding-constraint annotations of the model stack expect."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh_for(n_devices: int, model_par: int = None):
@@ -39,5 +35,5 @@ def make_mesh_for(n_devices: int, model_par: int = None):
         model_par = min(16, n_devices)
     while n_devices % model_par:
         model_par //= 2
-    return compat_make_mesh((n_devices // model_par, model_par),
+    return _auto_mesh((n_devices // model_par, model_par),
                             ("data", "model"))

@@ -160,9 +160,7 @@ def engine_distributed(prep, target, cfg: PipelineConfig, mesh=None, **ctx):
 
     r = cfg.recovery
     if mesh is None:
-        from repro.launch.mesh import compat_make_mesh
-
-        mesh = compat_make_mesh((jax.device_count(),), (r.axis,))
+        mesh = jax.make_mesh((jax.device_count(),), (r.axis,))
     status = dist_mod.recover_mixed(
         prep, mesh, axis=r.axis, block_size=r.block_size,
         max_candidates=r.max_candidates, chunk=cfg.chunk, cutoff=r.cutoff)

@@ -49,7 +49,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.device_graph import DeviceGraph
 from repro.core.graph import Graph, build_graph
 from repro.core.graph_ops import (coalesce_edges, propose_accept_matching,
-                                  segment_argmax, shard_map_compat,
+                                  segment_argmax,
                                   sharded_coalesce_edges, sharded_matching,
                                   sharded_segment_argmax)
 from repro.obs import get_metrics, get_tracer
@@ -100,6 +100,20 @@ class Hierarchy:
     @property
     def level_sizes(self) -> list:
         return [lev.n for lev in self.levels] + [self.coarse_n]
+
+    def arrays(self) -> tuple:
+        """The device arrays of the chain as a pytree — what a jitted solve
+        takes as an argument (see :meth:`with_arrays`)."""
+        return (tuple((lev.idx, lev.val, lev.diag, lev.agg)
+                      for lev in self.levels), self.coarse_chol)
+
+    def with_arrays(self, arrays: tuple) -> "Hierarchy":
+        """This hierarchy's static structure over ``arrays`` (the pytree of
+        :meth:`arrays`, possibly traced)."""
+        levels, chol = arrays
+        return dataclasses.replace(self, coarse_chol=chol, levels=tuple(
+            dataclasses.replace(lev, idx=i, val=v, diag=d, agg=a)
+            for lev, (i, v, d, a) in zip(self.levels, levels)))
 
 
 def subgraph(g: Graph, edge_mask: np.ndarray) -> Graph:
@@ -316,10 +330,14 @@ def sharded_contract(dg: DeviceGraph, mesh, axis: str = "data"
     w_p = pad(dg.weight, 0.0, np.float32)
     eids = pad(np.arange(m, dtype=np.int32), -1, np.int32)
 
-    fn = shard_map_compat(
+    # check_vma=False: the coarse edge list is an all_gather followed by
+    # the same merge on every shard, so it is replicated by construction,
+    # but its type stays "varying" (jax has no public invariant all_gather)
+    # and the default check would refuse the P() out_specs.
+    fn = jax.shard_map(
         _sharded_contract_core(dg.n, m, axis), mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis)),
-        out_specs=(P(), P(), P(), P(), P(), P(), P()))
+        out_specs=(P(), P(), P(), P(), P(), P(), P()), check_vma=False)
     _, agg, n_pairs, csrc, cdst, cw, m_coarse = fn(src_p, dst_p, w_p, eids)
     nc, mc = int(n_pairs), int(m_coarse)
     coarse = build_graph(nc, np.asarray(csrc[:mc]), np.asarray(cdst[:mc]),

@@ -130,9 +130,9 @@ class SolverService:
         ``matvec_impl`` selects the solve plane's kernel path — ``"fused"``
         (Pallas-fused V-cycle: batched spmv + fused Chebyshev + fused
         restrict+residual), ``"kernel"`` (per-column Pallas spmv), or
-        ``"ref"`` (jnp composition, the parity oracle); ``None``
-        auto-selects via :func:`~repro.solver.device_pcg.default_matvec_impl`
-        ("fused" when the kernels compile, "ref" under interpret).  The
+        ``"ref"`` (jnp composition); ``None`` takes
+        :func:`~repro.solver.device_pcg.default_matvec_impl` ("ref" on
+        every backend: Mosaic refuses the Pallas kernels today).  The
         impl joins the artifact key (schema v7).  ``interpret`` forces
         Pallas interpret/compiled mode for all kernels this service builds;
         ``None`` resolves from the backend (see
@@ -626,8 +626,7 @@ class SolverService:
             maxiter_col[j] = reqs[e].maxiter
         # The f32 device solve floors around 1e-7 relative residual; ask
         # it only for what it can deliver and let the f64 refinement
-        # passes close the rest (each pass multiplies the true residual
-        # by ~inner_tol).  Per column: a loose-tol request batched with
+        # passes close the rest.  Per column: a loose-tol request batched with
         # a strict one stops at its own contract instead of riding along
         # to the group minimum.
         inner_tol = jnp.asarray(
@@ -655,12 +654,20 @@ class SolverService:
         relres = np.linalg.norm(resid, axis=0) / bn
         while refinements < self.max_refine and np.any(relres > tol_col):
             rc = resid - resid.mean(axis=0)
+            # A correction need only close each column's gap to its tol:
+            # solved to tol / relres relative to ``rc`` it leaves a true
+            # residual of about tol (the loop aims at half its target).
+            # Asking it for tol itself would solve to ~tol^2 and cost
+            # about as many iterations as the first pass.
+            corr_tol = jnp.asarray(np.clip(
+                tol_col / np.maximum(relres, np.finfo(np.float64).tiny),
+                1e-5, 1.0).astype(np.float32))
             # corrections draw from each column's remaining budget
             with tracer.span("solver.refine", pass_=refinements + 1,
                              k=k, k_pad=k_pad), \
                     trace_annotation("solver.refine"):
                 corr = solve(jnp.asarray(rc.astype(np.float32)),
-                             tol=inner_tol,
+                             tol=corr_tol,
                              maxiter=jnp.asarray(np.maximum(
                                  maxiter_col - iters, 0)))
             x_new = x + np.asarray(corr.x, dtype=np.float64)

@@ -19,7 +19,6 @@ from repro.core import grid2d, prepare
 from repro.core.distributed import (build_outer_shards, pad_fill_value,
                                     partition_subtasks, recover_mixed)
 from repro.core.recovery import recover_serial
-from repro.launch.mesh import compat_make_mesh
 
 SCRIPT = textwrap.dedent("""
     import os
@@ -33,9 +32,8 @@ SCRIPT = textwrap.dedent("""
     from repro.core import grid2d, barabasi_albert, star_hub, prepare
     from repro.core.recovery import recover_serial
     from repro.core.distributed import recover_mixed, partition_subtasks
-    from repro.launch.mesh import compat_make_mesh
 
-    mesh = compat_make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",))
     cases = [
         ("grid", grid2d(15, 15, seed=1), None),
         ("ba", barabasi_albert(400, 3, seed=3), None),
@@ -78,7 +76,7 @@ def test_inner_engine_works_without_jax_lax_axis_size(monkeypatch):
     assert not hasattr(jax.lax, "axis_size")
     g = grid2d(9, 9, seed=2)
     prep = prepare(g, chunk=128)
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     # cutoff=1 routes every subtask through the inner engine
     st_mixed = recover_mixed(prep, mesh, chunk=128, cutoff=1)
     np.testing.assert_array_equal(recover_serial(prep.problem), st_mixed)
@@ -126,6 +124,6 @@ def test_outer_shards_accept_integer_scores():
 def test_recover_mixed_equals_serial_on_integer_scores():
     g = grid2d(9, 9, seed=4)
     prep = _int_score_prep(g)
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     st_mixed = recover_mixed(prep, mesh, chunk=128)
     np.testing.assert_array_equal(recover_serial(prep.problem), st_mixed)

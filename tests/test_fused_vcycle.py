@@ -181,27 +181,32 @@ def test_sharded_rejects_kernel_impl():
 # ---------------------------------------------------------------------------
 
 def test_resolve_interpret_priority(monkeypatch):
-    monkeypatch.delenv("REPRO_KERNEL_INTERPRET", raising=False)
-    # explicit bool wins over everything
-    assert resolve_interpret(True) is True
-    assert resolve_interpret(False) is False
-    # env var wins over backend sniffing
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")
-    assert resolve_interpret(None) is False
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
+    # an explicit bool wins on every backend
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert resolve_interpret(True) is True
+        assert resolve_interpret(False) is False
+    # None: compiled on a TPU, interpret everywhere else
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert resolve_interpret(None) is True
-    # backend default: interpret everywhere but TPU (this container: CPU)
-    monkeypatch.delenv("REPRO_KERNEL_INTERPRET")
-    assert resolve_interpret(None) is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    # no environment variable can put a kernel into interpret mode on a TPU
+    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
+    assert resolve_interpret(None) is False
 
 
 def test_default_matvec_impl_tracks_interpret(monkeypatch):
+    """The default matvec impl no longer follows the interpret resolution:
+    it is the declared ``"ref"`` on every backend, TPU included, and the
+    service takes it when no impl is named."""
     from repro.solver.device_pcg import default_matvec_impl
+    from repro.solver.service import SolverService
 
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
-    assert default_matvec_impl() == "ref"
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")
-    assert default_matvec_impl() == "fused"
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert default_matvec_impl() == "ref"
+        assert SolverService().matvec_impl == "ref"
 
 
 def test_cheby_coeffs_interval():

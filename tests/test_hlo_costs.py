@@ -78,19 +78,14 @@ def test_collective_bytes_counted():
     script = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import contextlib
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.core.distributed import _shard_map
         from repro.launch import hlo_costs
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",))
         def f(x):
             return jax.lax.psum(x, "data")
-        fn = _shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
-        ctx = (jax.set_mesh(mesh) if hasattr(jax, "set_mesh")
-               else contextlib.nullcontext())
-        with ctx:
+        fn = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
+        with jax.set_mesh(mesh):
             comp = jax.jit(fn).lower(
                 jax.ShapeDtypeStruct((1024,), jnp.float32)).compile()
         c = hlo_costs.analyze_hlo(comp.as_text())

@@ -31,12 +31,11 @@ SCRIPT = textwrap.dedent("""
     from repro.core import mesh2d, barabasi_albert, star_hub, prepare
     from repro.core.distributed import recover_mixed
     from repro.core.recovery import recover_serial
-    from repro.launch.mesh import compat_make_mesh
     from repro.pipeline import pdgrass_config
     from repro.solver import SolverService, build_hierarchy
 
     assert jax.device_count() == 8
-    mesh = compat_make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",))
     cfg = pdgrass_config(alpha=0.05, chunk=256)
     rebase = lambda x: np.asarray(x, np.float64) - np.asarray(x, np.float64)[0]
 

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 
 from repro.core import barabasi_albert, grid2d, mesh2d
 from repro.core.pcg import pcg_host
+from repro.pipeline import pdgrass_config
 from repro.solver import (LRUCache, SolveRequest, SolverService, batched_pcg,
                           build_hierarchy, ell_laplacian, graph_fingerprint,
                           make_matvec, make_solver)
@@ -78,7 +79,34 @@ def test_batched_pcg_columns_match_single_solves():
         np.testing.assert_allclose(_rebase(np.asarray(res.x)[:, j]),
                                    _rebase(np.asarray(one.x)[:, 0]),
                                    atol=1e-3)
-        assert int(np.asarray(res.iters)[j]) == int(np.asarray(one.iters)[0])
+        # the same recurrence per column, but XLA may order the f32
+        # reductions differently at another block width, so the stopping
+        # iteration can move by one
+        assert abs(int(np.asarray(res.iters)[j])
+                   - int(np.asarray(one.iters)[0])) <= 1
+
+
+def test_service_converges_where_residual_replacement_stalled():
+    """Every column of an 8-RHS request meets tol through the service.
+
+    The PCG loop used to swap its recurrence residual for the f32 product
+    ``b - A x`` every 50 iterations without resetting the search
+    direction.  That product's own error, about eps * ||A|| ||x||, exceeds
+    tol * ||b|| once the system is large or hard enough, and columns then
+    stalled at maxiter: on mesh2d(200, 200) with the default hierarchy 7 of
+    8 columns, and here (a 60 x 60 mesh whose ~500-vertex coarse level makes
+    the stall appear at a size that keeps the test quick) one of 8."""
+    g = mesh2d(60, 60, seed=0)
+    svc = SolverService(pipeline=pdgrass_config(alpha=0.05, chunk=512),
+                        coarse_n=1000)
+    B = np.random.default_rng(0).standard_normal((g.n, 8)).astype(np.float32)
+    res = svc.solve(g, B, tol=1e-5)
+    b = B.astype(np.float64) - B.astype(np.float64).mean(axis=0)
+    relres = (np.linalg.norm(b - g.laplacian_matvec(res.x), axis=0)
+              / np.linalg.norm(b, axis=0))
+    assert res.converged
+    assert np.all(relres <= 1e-5), relres
+    assert np.all(res.iters < 2000), res.iters
 
 
 def test_kernel_and_ref_paths_agree_end_to_end():
