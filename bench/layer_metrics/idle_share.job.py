@@ -1,0 +1,9 @@
+"""Device idle share over one job (%): ``1 - busy / window`` of the
+profiled window."""
+
+
+def read(ctx):
+    t = ctx.get("trace")
+    if not t or not t["window_s"] or not t["busy_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
