@@ -221,23 +221,23 @@ class Tracer:
                     return _DroppedSpan(self)
         return _Span(self, name, attrs or None)
 
-    def instant(self, name: str, **attrs) -> None:
-        """A zero-duration marker event (Chrome "i" phase).  Instants inside
-        a sampled-out trace are dropped with it."""
+    def complete(self, name: str, t0_ns: int, dur_ns: int, **attrs) -> None:
+        """Record a span that has already ended — one reported by a
+        callback after the fact (a compile, timed by JAX).  It nests under
+        whatever span is open on the calling thread, and is dropped with
+        a sampled-out trace."""
         if not self.enabled:
             return
         tls = self._tls
         if getattr(tls, "drop_depth", 0) > 0:
             return
-        self._record(name, time.perf_counter_ns(), None,
-                     getattr(tls, "depth", 0), attrs or None)
+        self._record(name, t0_ns, dur_ns, getattr(tls, "depth", 0),
+                     attrs or None)
 
-    def _record(self, name: str, t0_ns: int, dur_ns: Optional[int],
+    def _record(self, name: str, t0_ns: int, dur_ns: int,
                 depth: int, args: Optional[Dict[str, Any]]) -> None:
-        ev = {"name": name, "ts_ns": t0_ns, "tid": threading.get_ident(),
-              "depth": depth}
-        if dur_ns is not None:
-            ev["dur_ns"] = dur_ns
+        ev = {"name": name, "ts_ns": t0_ns, "dur_ns": dur_ns,
+              "tid": threading.get_ident(), "depth": depth}
         if args:
             ev["args"] = args
         with self._lock:
@@ -261,27 +261,24 @@ class Tracer:
         """All recorded durations (ms) of spans named ``name``."""
         with self._lock:
             return [ev["dur_ns"] / 1e6 for ev in self._events
-                    if ev["name"] == name and "dur_ns" in ev]
+                    if ev["name"] == name]
 
     def to_chrome(self) -> dict:
         """Chrome trace-event JSON object (Perfetto-loadable).
 
-        Complete ("X") events carry microsecond ``ts``/``dur``; instants map
-        to thread-scoped "i" events.  All events share this process's pid.
+        Complete ("X") events with microsecond ``ts``/``dur``.  All events
+        share this process's pid.
         """
         trace_events = []
         for ev in self.events():
             out = {
                 "name": ev["name"],
-                "ph": "X" if "dur_ns" in ev else "i",
+                "ph": "X",
                 "ts": ev["ts_ns"] / 1e3,
+                "dur": ev["dur_ns"] / 1e3,
                 "pid": self._pid,
                 "tid": ev["tid"],
             }
-            if "dur_ns" in ev:
-                out["dur"] = ev["dur_ns"] / 1e3
-            else:
-                out["s"] = "t"
             if "args" in ev:
                 out["args"] = {k: _jsonable(v) for k, v in ev["args"].items()}
             trace_events.append(out)

@@ -18,6 +18,7 @@ from __future__ import annotations
 import math
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -56,6 +57,8 @@ class Pipeline:
 
             with tracer.span("pipeline.tree", kind=cfg.tree.kind):
                 tree = TREE_STAGES[cfg.tree.kind](n, src, dst, w, cfg.tree)
+                if tracer.enabled:      # end the span on the tree itself
+                    jax.block_until_ready(tree)
             with tracer.span("pipeline.lifting"):
                 lift = lift_mod.build_lifting(n, tree.parent, tree.parent_w,
                                               tree.depth)

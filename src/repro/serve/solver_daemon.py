@@ -47,7 +47,9 @@ Three mechanisms, one thread:
 
 Observability (all in the service's metrics registry, ``serve.*``): a
 ``serve.flush_cycle`` span per cycle (samplable in production via
-``Tracer(sample_rate=...)``), a ``serve.queue_depth`` gauge,
+``Tracer(sample_rate=...)``) carrying its requests' ticket ids and queue
+waits, a ``serve.batch_wait`` span while work waits out its batch
+deadline, a ``serve.queue_depth`` gauge,
 ``serve.queue_wait_ms`` / ``serve.e2e_ms`` latency histograms, and a
 ``serve.slo_violations`` counter incremented when a flush group's
 end-to-end latency exceeds the ``max_batch_delay_ms``-derived budget.
@@ -314,6 +316,7 @@ class SolverDaemon:
         metrics.set_gauge("serve.queue_depth", len(self._queue))
 
     def _run(self) -> None:
+        tracer = get_tracer()
         while True:
             with self._cond:
                 trigger = None
@@ -344,7 +347,9 @@ class SolverDaemon:
                         # wake-up time can only be the batch deadline
                         trigger = "deadline"
                         break
-                    self._cond.wait(wait)
+                    # work is queued and the device waits for the batch
+                    with tracer.span("serve.batch_wait"):
+                        self._cond.wait(wait)
                 if trigger == "drain":
                     break   # settle the remaining queue below, then exit
                 batch = self._select_batch_locked()
@@ -453,6 +458,12 @@ class SolverDaemon:
                          requests=len(batch),
                          columns=sum(e.cols for e in batch),
                          tenants=len({e.tenant for e in batch})) as sp:
+            if tracer.enabled:
+                # one id per request across threads: the same ticket ids
+                # tag the solver.group spans that serve them
+                sp.set(tickets=[int(e.ticket) for e in batch],
+                       waits_ms=[(t_start - e.t_submit) * 1e3
+                                 for e in batch])
             self.service._solve_batch(
                 [(e.ticket, e.handle, e.request) for e in batch])
             sp.set(queue_wait_ms=round((t_start - batch[0].t_submit) * 1e3, 3))

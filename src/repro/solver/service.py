@@ -44,7 +44,7 @@ import numpy as np
 
 from repro.core.graph import Graph
 from repro.obs import Metrics, get_metrics, get_tracer
-from repro.obs.device import trace_annotation
+from repro.obs.device import install_compile_listener, trace_annotation
 from repro.pipeline import PipelineConfig, pdgrass_config
 from repro.pipeline import validate as validate_config
 from repro.solver import cache as cache_mod
@@ -192,6 +192,7 @@ class SolverService:
         # hierarchy, distributed) lands in the process-wide registry and is
         # merged into ``stats()["metrics"]`` read-only.
         self.metrics = metrics if metrics is not None else Metrics()
+        install_compile_listener()    # jax.compile spans and counters
         self.cache = LRUCache(capacity=cache_capacity, disk_dir=disk_dir,
                               disk_max_entries=disk_max_entries,
                               disk_max_bytes=disk_max_bytes,
@@ -583,10 +584,18 @@ class SolverService:
 
     def _solve_group_inner(self, entries, config, key, g, config_digest,
                            tracer, group_span):
-        """Body of :meth:`_solve_group`, factored out so the whole group —
-        artifact fetch, batched solve, refinement — nests under one
-        ``solver.group`` span."""
+        """Body of :meth:`_solve_group`, factored out so the whole group
+        nests under one ``solver.group`` span, whose children name every
+        phase in order: ``solver.artifacts``, ``solver.stack``,
+        ``solver.solve``, ``solver.residual``, then ``solver.refine`` and
+        ``solver.residual`` per refinement pass, and ``solver.resolve``.
+        ``solver.solve`` and ``solver.refine`` each hold one device call,
+        from the copy of its right-hand side to the read-back of its
+        answer, and carry ``pass``, ``k``, ``k_pad`` and ``loops`` (the
+        call's while-loop trips)."""
         handle = entries[0][1]
+        if tracer.enabled:
+            group_span.set(tickets=[int(t) for t, _, _ in entries])
         with tracer.span("solver.artifacts", config=config_digest) as asp:
             t0 = time.perf_counter()
             _, artifacts, source = self.artifacts(handle, key=key,
@@ -595,50 +604,52 @@ class SolverService:
             solve = self._solver_for(key, artifacts)
             asp.set(source=source)
 
-        cols, owner = [], []       # owner[j] = (entry-idx, col-in-request)
-        for e, (_, _, req) in enumerate(entries):
-            b = np.asarray(req.b, dtype=np.float32)
-            b = b[:, None] if b.ndim == 1 else b
-            for j in range(b.shape[1]):
-                cols.append(b[:, j])
-                owner.append((e, j))
-        k = len(cols)
-        k_pad = _next_pow2(k)
-        B = np.zeros((g.n, k_pad), np.float32)
-        B[:, :k] = np.stack(cols, axis=1)
-        # L is singular with nullspace = constants: only the mean-zero
-        # component of b is solvable.  Center here so the residual
-        # measurement below targets the solvable system (else the
-        # unsolvable mean would read as non-convergence).
-        B -= B.mean(axis=0)
-        # Per-column tolerance and iteration budget: each request keeps
-        # its own contract even when batched with stricter/larger
-        # neighbors.  Padding columns are inert BY CONSTRUCTION — tol=inf
-        # and maxiter=0 mean they can never drive batched_pcg's while-loop
-        # (done from iteration zero) nor the refinement pass (zero
-        # remaining budget, relres 0 <= inf), independent of the separate
-        # zero-RHS short-circuit.
-        reqs = [req for _, _, req in entries]
-        tol_col = np.full(k_pad, np.inf)
-        maxiter_col = np.zeros(k_pad, np.int32)
-        for j, (e, _) in enumerate(owner):
-            tol_col[j] = reqs[e].tol
-            maxiter_col[j] = reqs[e].maxiter
-        # The f32 device solve floors around 1e-7 relative residual; ask
-        # it only for what it can deliver and let the f64 refinement
-        # passes close the rest.  Per column: a loose-tol request batched with
-        # a strict one stops at its own contract instead of riding along
-        # to the group minimum.
-        inner_tol = jnp.asarray(
-            np.maximum(tol_col, 1e-5).astype(np.float32))
+        with tracer.span("solver.stack"):
+            cols, owner = [], []   # owner[j] = (entry-idx, col-in-request)
+            for e, (_, _, req) in enumerate(entries):
+                b = np.asarray(req.b, dtype=np.float32)
+                b = b[:, None] if b.ndim == 1 else b
+                for j in range(b.shape[1]):
+                    cols.append(b[:, j])
+                    owner.append((e, j))
+            k = len(cols)
+            k_pad = _next_pow2(k)
+            B = np.zeros((g.n, k_pad), np.float32)
+            B[:, :k] = np.stack(cols, axis=1)
+            # L is singular with nullspace = constants: only the mean-zero
+            # component of b is solvable.  Center here so the residual
+            # measurement below targets the solvable system (else the
+            # unsolvable mean would read as non-convergence).
+            B -= B.mean(axis=0)
+            # Per-column tolerance and iteration budget: each request keeps
+            # its own contract even when batched with stricter/larger
+            # neighbors.  Padding columns are inert BY CONSTRUCTION —
+            # tol=inf and maxiter=0 mean they can never drive batched_pcg's
+            # while-loop (done from iteration zero) nor the refinement pass
+            # (zero remaining budget, relres 0 <= inf), independent of the
+            # separate zero-RHS short-circuit.
+            reqs = [req for _, _, req in entries]
+            tol_col = np.full(k_pad, np.inf)
+            maxiter_col = np.zeros(k_pad, np.int32)
+            for j, (e, _) in enumerate(owner):
+                tol_col[j] = reqs[e].tol
+                maxiter_col[j] = reqs[e].maxiter
+            # The f32 device solve floors around 1e-7 relative residual;
+            # ask it only for what it can deliver and let the f64
+            # refinement passes close the rest.  Per column: a loose-tol
+            # request batched with a strict one stops at its own contract
+            # instead of riding along to the group minimum.
+            inner_tol = np.maximum(tol_col, 1e-5).astype(np.float32)
 
         t0 = time.perf_counter()
-        with tracer.span("solver.solve", k=k, k_pad=k_pad, n=g.n), \
-                trace_annotation("solver.solve"):
-            res = solve(jnp.asarray(B), tol=inner_tol,
+        with tracer.span("solver.solve", **{"pass": 0}, k=k, k_pad=k_pad,
+                         n=g.n) as sp, trace_annotation("solver.solve"):
+            res = solve(jnp.asarray(B), tol=jnp.asarray(inner_tol),
                         maxiter=jnp.asarray(maxiter_col))
             x = np.asarray(res.x, dtype=np.float64)
             iters = np.asarray(res.iters).copy()
+            if tracer.enabled:
+                sp.set(loops=int(iters.max()))
 
         # Mixed-precision iterative refinement: the f32 device solve hits
         # its attainable-accuracy floor on large/ill-conditioned graphs,
@@ -646,79 +657,98 @@ class SolverService:
         # for the correction on the device until tol is genuinely met.
         # The residual matvec runs over the Graph's own CSR arrays
         # (numpy f64, no scipy on the solve path).
-        B64 = B.astype(np.float64)
-        bn = np.maximum(np.linalg.norm(B64, axis=0),
-                        np.finfo(np.float64).tiny)
+        with tracer.span("solver.residual", **{"pass": 0}):
+            B64 = B.astype(np.float64)
+            bn = np.maximum(np.linalg.norm(B64, axis=0),
+                            np.finfo(np.float64).tiny)
+            resid = B64 - g.laplacian_matvec(x)
+            relres = np.linalg.norm(resid, axis=0) / bn
+            rhs = self._correction(resid, relres, tol_col, 0)
         refinements = 0
-        resid = B64 - g.laplacian_matvec(x)
-        relres = np.linalg.norm(resid, axis=0) / bn
-        while refinements < self.max_refine and np.any(relres > tol_col):
-            rc = resid - resid.mean(axis=0)
-            # A correction need only close each column's gap to its tol:
-            # solved to tol / relres relative to ``rc`` it leaves a true
-            # residual of about tol (the loop aims at half its target).
-            # Asking it for tol itself would solve to ~tol^2 and cost
-            # about as many iterations as the first pass.
-            corr_tol = jnp.asarray(np.clip(
-                tol_col / np.maximum(relres, np.finfo(np.float64).tiny),
-                1e-5, 1.0).astype(np.float32))
+        while rhs is not None:
+            rc, corr_tol = rhs
+            refinements += 1
             # corrections draw from each column's remaining budget
-            with tracer.span("solver.refine", pass_=refinements + 1,
-                             k=k, k_pad=k_pad), \
+            with tracer.span("solver.refine", **{"pass": refinements}, k=k,
+                             k_pad=k_pad) as sp, \
                     trace_annotation("solver.refine"):
-                corr = solve(jnp.asarray(rc.astype(np.float32)),
-                             tol=corr_tol,
+                corr = solve(jnp.asarray(rc), tol=jnp.asarray(corr_tol),
                              maxiter=jnp.asarray(np.maximum(
                                  maxiter_col - iters, 0)))
-            x_new = x + np.asarray(corr.x, dtype=np.float64)
-            resid_new = B64 - g.laplacian_matvec(x_new)
-            relres_new = np.linalg.norm(resid_new, axis=0) / bn
-            # accept per column whenever the correction improved it ...
-            take = relres_new < relres
-            x = np.where(take, x_new, x)
-            resid = np.where(take, resid_new, resid)
-            halved = np.any(relres_new < 0.5 * relres)
-            relres = np.where(take, relres_new, relres)
-            iters = iters + np.asarray(corr.iters)
-            refinements += 1
-            if not halved:
-                break  # ... but stop once passes stall at the f32 floor
+                dx = np.asarray(corr.x, dtype=np.float64)
+                corr_iters = np.asarray(corr.iters)
+                if tracer.enabled:
+                    sp.set(loops=int(corr_iters.max()))
+            with tracer.span("solver.residual", **{"pass": refinements}):
+                x_new = x + dx
+                resid_new = B64 - g.laplacian_matvec(x_new)
+                relres_new = np.linalg.norm(resid_new, axis=0) / bn
+                # accept per column whenever the correction improved it ...
+                take = relres_new < relres
+                x = np.where(take, x_new, x)
+                resid = np.where(take, resid_new, resid)
+                halved = np.any(relres_new < 0.5 * relres)
+                relres = np.where(take, relres_new, relres)
+                iters = iters + corr_iters
+                # ... but stop once passes stall at the f32 floor
+                rhs = (self._correction(resid, relres, tol_col, refinements)
+                       if halved else None)
         solve_ms = (time.perf_counter() - t0) * 1e3
-        with self._lock:
-            self._timing["setup_ms"] += setup_ms
-            self._timing["solve_ms"] += solve_ms
-            self._conv_digests.add(config_digest)
-        conv = relres <= tol_col
-        # Convergence telemetry, fetched ONCE per flush group from arrays
-        # this path already materializes (iters/relres came back with the
-        # solution — no extra device round-trip).  Padding columns are
-        # excluded: only the k real right-hand sides count.
-        m = self.metrics
-        m.observe_many(f"solver.pcg.iters.{config_digest}",
-                       np.asarray(iters[:k], dtype=np.float64))
-        m.observe_many(f"solver.pcg.relres.{config_digest}",
-                       np.asarray(relres[:k], dtype=np.float64))
-        m.observe(f"solver.latency.setup_ms.{config_digest}", setup_ms)
-        m.observe(f"solver.latency.solve_ms.{config_digest}", solve_ms)
-        m.inc("solver.refinement_passes", refinements)
-        if not bool(conv[:k].all()):
-            m.inc("solver.unconverged_columns",
-                  int(k - int(conv[:k].sum())))
-        group_span.set(k=k, k_pad=k_pad, source=source,
-                       refinements=refinements,
-                       max_iters=int(np.max(iters[:k])) if k else 0,
-                       converged=bool(conv[:k].all()))
-        out: Dict[SolveTicket, SolveResponse] = {}
-        for e, (ticket, _, req) in enumerate(entries):
-            mine = [j for j, (ee, _) in enumerate(owner) if ee == e]
-            xs = x[:, mine]
-            if np.asarray(req.b).ndim == 1:
-                xs = xs[:, 0]
-            response = SolveResponse(
-                x=xs, iters=iters[mine], relres=relres[mine],
-                converged=bool(conv[mine].all()), cache=source,
-                refinements=refinements, setup_ms=setup_ms,
-                solve_ms=solve_ms, config=config_digest)
-            ticket._resolve(response)
-            out[ticket] = response
-        return out
+
+        with tracer.span("solver.resolve"):
+            with self._lock:
+                self._timing["setup_ms"] += setup_ms
+                self._timing["solve_ms"] += solve_ms
+                self._conv_digests.add(config_digest)
+            conv = relres <= tol_col
+            # Convergence telemetry, fetched ONCE per flush group from arrays
+            # this path already materializes (iters/relres came back with the
+            # solution — no extra device round-trip).  Padding columns are
+            # excluded: only the k real right-hand sides count.
+            m = self.metrics
+            m.observe_many(f"solver.pcg.iters.{config_digest}",
+                           np.asarray(iters[:k], dtype=np.float64))
+            m.observe_many(f"solver.pcg.relres.{config_digest}",
+                           np.asarray(relres[:k], dtype=np.float64))
+            m.observe(f"solver.latency.setup_ms.{config_digest}", setup_ms)
+            m.observe(f"solver.latency.solve_ms.{config_digest}", solve_ms)
+            m.inc("solver.refinement_passes", refinements)
+            if not bool(conv[:k].all()):
+                m.inc("solver.unconverged_columns",
+                      int(k - int(conv[:k].sum())))
+            group_span.set(k=k, k_pad=k_pad, source=source,
+                           refinements=refinements,
+                           max_iters=int(np.max(iters[:k])) if k else 0,
+                           converged=bool(conv[:k].all()))
+            out: Dict[SolveTicket, SolveResponse] = {}
+            for e, (ticket, _, req) in enumerate(entries):
+                mine = [j for j, (ee, _) in enumerate(owner) if ee == e]
+                xs = x[:, mine]
+                if np.asarray(req.b).ndim == 1:
+                    xs = xs[:, 0]
+                response = SolveResponse(
+                    x=xs, iters=iters[mine], relres=relres[mine],
+                    converged=bool(conv[mine].all()), cache=source,
+                    refinements=refinements, setup_ms=setup_ms,
+                    solve_ms=solve_ms, config=config_digest)
+                ticket._resolve(response)
+                out[ticket] = response
+            return out
+
+    def _correction(self, resid, relres, tol_col, refinements):
+        """The next refinement pass's right-hand side and per-column tol
+        (both f32, as the device solve takes them), or ``None`` when no
+        pass is due: the budget of passes is spent or every column meets
+        its tol."""
+        if refinements >= self.max_refine or not np.any(relres > tol_col):
+            return None
+        rc = resid - resid.mean(axis=0)
+        # A correction need only close each column's gap to its tol:
+        # solved to tol / relres relative to ``rc`` it leaves a true
+        # residual of about tol (the loop aims at half its target).
+        # Asking it for tol itself would solve to ~tol^2 and cost
+        # about as many iterations as the first pass.
+        corr_tol = np.clip(
+            tol_col / np.maximum(relres, np.finfo(np.float64).tiny),
+            1e-5, 1.0)
+        return rc.astype(np.float32), corr_tol.astype(np.float32)

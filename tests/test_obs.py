@@ -16,6 +16,7 @@ Covers the observability contracts end to end:
 """
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -24,6 +25,7 @@ from repro.core import mesh2d
 from repro.obs import (Counter, Gauge, Histogram, Metrics, get_metrics,
                        get_tracer)
 from repro.obs import trace as trace_mod
+from repro.obs.device import install_compile_listener
 from repro.obs.trace import NOOP_SPAN, Tracer
 from repro.solver import SolveRequest, SolverService
 from repro.solver.cache import content_fingerprint
@@ -69,7 +71,7 @@ def test_disabled_span_is_true_noop(monkeypatch):
         "disabled span() must return the shared singleton")
     with tr.span("x") as sp:
         sp.set(result=1)        # must be accepted and discarded
-    tr.instant("marker")
+    tr.complete("marker", 0, 1)
     assert tr.events() == []
     tr.enable()
     with tr.span("y"):
@@ -133,13 +135,13 @@ def test_chrome_export_roundtrips_through_json(tmp_path, traced):
                      arr=np.arange(2)):
         with traced.span("child"):
             pass
-    traced.instant("mark", note="hi")
+    traced.complete("mark", 0, 5, note="hi")
     path = tmp_path / "trace.json"
     traced.export_chrome(str(path))
     doc = json.loads(path.read_text())      # strict round-trip
     evs = {ev["name"]: ev for ev in doc["traceEvents"]}
     assert evs["parent"]["ph"] == "X" and evs["child"]["ph"] == "X"
-    assert evs["mark"]["ph"] == "i"
+    assert evs["mark"]["ph"] == "X" and evs["mark"]["args"] == {"note": "hi"}
     # numpy attrs degraded to plain JSON scalars/strings
     assert evs["parent"]["args"]["n"] == 3
     assert evs["parent"]["args"]["f"] == pytest.approx(0.5)
@@ -334,6 +336,169 @@ def test_warm_solve_records_no_spans_when_disabled(traffic):
     assert tr.events() == []
 
 
+# -- phases of a solve group -------------------------------------------------
+
+
+def _children(events, parent):
+    """Direct children of ``parent`` on its thread, in time order."""
+    p0, p1 = parent["ts_ns"], parent["ts_ns"] + parent["dur_ns"]
+    return sorted((e for e in events if e["tid"] == parent["tid"]
+                   and e["depth"] == parent["depth"] + 1
+                   and p0 <= e["ts_ns"]
+                   and e["ts_ns"] + e["dur_ns"] <= p1),
+                  key=lambda e: e["ts_ns"])
+
+
+@pytest.fixture(scope="module")
+def refined():
+    """One traced group at tol 1e-6, below the f32 pass's floor of 1e-5,
+    so it takes at least one refinement pass."""
+    g = mesh2d(14, 14, seed=0)
+    svc = SolverService(alpha=0.1, max_refine=3)
+    h = svc.register(g)
+    svc.solve(h, _rhs(g, k=3), tol=1e-6)        # build and compile
+    tr = get_tracer()
+    was = tr.enabled
+    tr.enable()
+    tr.clear()
+    ticket = svc.submit(SolveRequest(graph=h, b=_rhs(g, k=3, seed=4),
+                                     tol=1e-6))
+    svc.flush()
+    events = tr.events()
+    tr.clear()
+    tr.enabled = was
+    return ticket, ticket.result(), events
+
+
+def test_group_names_every_phase_in_order(refined):
+    ticket, resp, events = refined
+    assert resp.converged and resp.refinements >= 1
+    group = next(e for e in events if e["name"] == "solver.group")
+    assert group["args"]["tickets"] == [int(ticket)]
+    kids = _children(events, group)
+    names = [e["name"].split(".", 1)[1] for e in kids]
+    assert names == (["artifacts", "stack", "solve", "residual"]
+                     + ["refine", "residual"] * resp.refinements
+                     + ["resolve"])
+    calls = [e for e in kids if e["name"] in ("solver.solve",
+                                              "solver.refine")]
+    assert [c["args"]["pass"] for c in calls] == list(
+        range(resp.refinements + 1))
+    assert [e["args"]["pass"] for e in kids
+            if e["name"] == "solver.residual"] == list(
+        range(resp.refinements + 1))
+    for c in calls:
+        assert {"pass", "k", "k_pad", "loops"} <= set(c["args"])
+        assert (c["args"]["k"], c["args"]["k_pad"]) == (3, 4)
+    # the loops of the calls add up to the slowest column's iterations
+    assert calls[0]["args"]["loops"] >= 1
+    assert sum(c["args"]["loops"] for c in calls) >= int(resp.iters.max())
+
+
+def test_tree_span_ends_on_the_tree(monkeypatch, traced):
+    """With the tracer on, ``pipeline.tree`` waits for the tree stage's
+    outputs before it ends; with it off, nothing waits."""
+    import jax
+
+    from repro.pipeline import Pipeline, pdgrass_config
+
+    real, waits = jax.block_until_ready, []
+
+    def block_until_ready(x):
+        out = real(x)
+        waits.append(time.perf_counter_ns())
+        return out
+
+    monkeypatch.setattr(jax, "block_until_ready", block_until_ready)
+    g = mesh2d(8, 8, seed=2)
+    Pipeline(pdgrass_config(alpha=0.1)).prepare(g)
+    tree = next(e for e in traced.events() if e["name"] == "pipeline.tree")
+    assert any(tree["ts_ns"] <= t <= tree["ts_ns"] + tree["dur_ns"]
+               for t in waits)
+    traced.disable()
+    del waits[:]
+    Pipeline(pdgrass_config(alpha=0.1)).prepare(g)
+    assert waits == []
+
+
+class _ReadClock:
+    """An answer array that notes the time it is read into numpy."""
+
+    def __init__(self, arr, reads):
+        self._arr, self._reads = arr, reads
+
+    def __array__(self, dtype=None, copy=None):
+        self._reads.append(time.perf_counter_ns())
+        return np.asarray(self._arr, dtype=dtype)
+
+
+def test_device_call_spans_end_on_the_answer(monkeypatch, traced):
+    """Each solve and refine span holds the read-back of its answer."""
+    import repro.solver.service as service
+
+    real, reads = service.make_solver, []
+
+    def make_solver(*a, **k):
+        fn = real(*a, **k)
+
+        def solve(b, tol=1e-5, maxiter=2000):
+            res = fn(b, tol=tol, maxiter=maxiter)
+            return res._replace(x=_ReadClock(res.x, reads))
+
+        return solve
+
+    monkeypatch.setattr(service, "make_solver", make_solver)
+    g = mesh2d(14, 14, seed=0)
+    svc = SolverService(alpha=0.1, max_refine=3)
+    resp = svc.solve(svc.register(g), _rhs(g, k=2, seed=5), tol=1e-6)
+    assert resp.converged and resp.refinements >= 1
+    calls = [e for e in traced.events()
+             if e["name"] in ("solver.solve", "solver.refine")]
+    assert [e["name"] for e in calls].count("solver.refine") == \
+        resp.refinements
+    assert len(reads) == len(calls)
+    for e, t in zip(sorted(calls, key=lambda e: e["ts_ns"]), reads):
+        assert e["ts_ns"] <= t <= e["ts_ns"] + e["dur_ns"], e["name"]
+
+
+def _fresh_jit(scale):
+    """A function JAX has not compiled before in this process."""
+    import jax
+
+    def scaled_shift(x):
+        return x * scale + 1.0
+
+    return jax.jit(scaled_shift)
+
+
+def test_compile_is_a_span_under_the_open_one(traced):
+    assert install_compile_listener() and install_compile_listener()
+    compiles = get_metrics().counter("jax.compiles")
+    before = compiles.value
+    with traced.span("outer"):
+        _fresh_jit(3.0)(np.ones(7, np.float32)).block_until_ready()
+    evs = traced.events()
+    spans = [e for e in evs if e["name"] == "jax.compile"]
+    assert len(spans) == 1 and compiles.value == before + 1
+    sp, outer = spans[0], next(e for e in evs if e["name"] == "outer")
+    assert sp["args"] == {"fun": "jit(scaled_shift)", "cache_hit": False}
+    assert sp["depth"] == 1 and sp["tid"] == outer["tid"]
+    assert outer["ts_ns"] <= sp["ts_ns"] and \
+        sp["ts_ns"] + sp["dur_ns"] <= outer["ts_ns"] + outer["dur_ns"]
+
+
+def test_compile_counts_but_records_nothing_when_disabled():
+    install_compile_listener()
+    tr = get_tracer()
+    assert not tr.enabled
+    tr.clear()
+    compiles = get_metrics().counter("jax.compiles")
+    before = compiles.value
+    _fresh_jit(5.0)(np.ones(9, np.float32)).block_until_ready()
+    assert compiles.value == before + 1
+    assert tr.events() == []
+
+
 def test_counter_and_gauge_types_exported():
     assert isinstance(Metrics().counter("x"), Counter)
     assert isinstance(Metrics().gauge("y"), Gauge)
@@ -353,7 +518,8 @@ def test_sampled_tracer_records_every_nth_root_span():
 
 def test_sampling_decision_covers_the_whole_root_tree():
     """A dropped root suppresses everything beneath it — nested spans and
-    instants never sample independently, so recorded trees stay complete."""
+    completed spans never sample independently, so recorded trees stay
+    complete."""
     tr = Tracer(enabled=True, sample_rate=0.5)
     for i in range(4):
         with tr.span(f"root{i}") as root:
@@ -362,7 +528,7 @@ def test_sampling_decision_covers_the_whole_root_tree():
                 c.set(deep=True)
                 with tr.span("grandchild"):
                     pass
-            tr.instant(f"marker{i}")
+            tr.complete(f"marker{i}", 0, 1)
     names = tr.span_names()
     # roots 0 and 2 recorded with their full subtrees; 1 and 3 vanish whole
     assert names.count("child") == 2 == names.count("grandchild")

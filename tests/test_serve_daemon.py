@@ -344,6 +344,60 @@ def test_flush_cycle_span_emitted(svc):
         tr.enabled = was
 
 
+def test_flush_cycle_spans_carry_ticket_ids_and_waits(svc):
+    """Every request can be followed from its cycle to its group by ticket
+    id; the flusher's wait for a batch deadline is a span of its own."""
+    service, h = svc
+    tr = get_tracer()
+    was = tr.enabled
+    tr.enable()
+    tr.clear()
+    try:
+        with SolverDaemon(service, max_batch_delay_ms=DELAY_MS) as d:
+            tickets = [d.submit(SolveRequest(graph=h, b=_rhs(h.n, seed=s)))
+                       for s in range(410, 414)]
+            for t in tickets:
+                assert t.result(timeout=30.0).converged
+        evs = tr.events()
+    finally:
+        tr.clear()
+        tr.enabled = was
+    cycles = [e for e in evs if e["name"] == "serve.flush_cycle"]
+    seen = []
+    for c in cycles:
+        ids, waits = c["args"]["tickets"], c["args"]["waits_ms"]
+        assert len(ids) == len(waits) == c["args"]["requests"]
+        assert all(w >= 0 for w in waits)
+        seen += ids
+    assert sorted(seen) == sorted(int(t) for t in tickets)
+    grouped = [t for e in evs if e["name"] == "solver.group"
+               for t in e["args"]["tickets"]]
+    assert sorted(grouped) == sorted(seen)
+    waits = [e for e in evs if e["name"] == "serve.batch_wait"]
+    assert waits and all(e["depth"] == 0 for e in waits)
+
+
+def test_disabled_tracer_allocates_no_span_in_a_cycle(svc, monkeypatch):
+    """The daemon's and the group's spans stay free with the tracer off."""
+    from repro.obs import trace as trace_mod
+
+    made = {"n": 0}
+    real_span = trace_mod._Span
+
+    class Spy(real_span):
+        def __init__(self, *a, **kw):
+            made["n"] += 1
+            real_span.__init__(self, *a, **kw)
+
+    monkeypatch.setattr(trace_mod, "_Span", Spy)
+    service, h = svc
+    assert not get_tracer().enabled
+    with SolverDaemon(service, max_batch_delay_ms=10.0) as d:
+        t = d.submit(SolveRequest(graph=h, b=_rhs(h.n, 2, seed=420)))
+        assert t.result(timeout=30.0).converged
+    assert made["n"] == 0
+
+
 def test_constructor_validation(svc):
     service, _ = svc
     with pytest.raises(ValueError, match="max_batch_delay_ms"):
