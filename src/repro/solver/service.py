@@ -20,9 +20,10 @@ same mesh coexist in one flush and each hit the right artifacts.
 ahead of traffic; ``stats()`` snapshots the cache, store, scheduler, and
 per-config solve counters.
 
-RHS batches are padded to the next power of two so the jit cache sees a
-handful of shapes instead of one per request count (the slot idiom of the
-LM engine: fixed slots, variable occupancy).
+RHS batches are padded to the next power of two, and to at least two
+columns, so the jit cache sees a handful of shapes instead of one per
+request count (the slot idiom of the LM engine: fixed slots, variable
+occupancy); see :func:`_call_width`.
 
 v1 compatibility: ``submit``/``solve`` still accept raw ``Graph``s (they
 are registered on the fly), tickets subclass ``int`` so ``flush()[ticket]``
@@ -71,8 +72,17 @@ from repro.solver.requests import (AdmissionError, GraphHandle, GraphStore,
 _SCHEMA = "solver-v7"
 
 
-def _next_pow2(k: int) -> int:
-    p = 1
+# Narrowest device call.  On a TPU v5e a width-1 solve costs about 3.3x
+# the per-iteration time of any width from 2 to 32, so a lone right-hand
+# side runs as a width-2 call with one inert padding column.
+_MIN_WIDTH = 2
+
+
+def _call_width(k: int) -> int:
+    """Columns of the device call that solves ``k`` right-hand sides: the
+    next power of two, at least ``_MIN_WIDTH``.  Flushes stack to it and
+    ``warmup`` compiles it, so the two always agree."""
+    p = _MIN_WIDTH
     while p < k:
         p *= 2
     return p
@@ -306,8 +316,9 @@ class SolverService:
         "mem"/"disk" mean the cache already held it.
 
         ``widths`` additionally jit-warms the solve itself: for every
-        requested RHS width the corresponding power-of-two slot bucket runs
-        one zero-RHS solve (a zero column converges in zero iterations, so
+        requested RHS width the slot bucket a flush pads it to
+        (:func:`_call_width`: a power of two, at least two) runs one
+        zero-RHS solve (a zero column converges in zero iterations, so
         the cost is pure XLA compilation), moving compile time out of the
         first real flush.  The cumulative compile wall time lands in
         ``stats()["timing"]["warmup_compile_ms"]`` — compare against
@@ -316,7 +327,7 @@ class SolverService:
         sources: Dict[str, str] = {}
         if widths is not None and any(int(w) < 1 for w in widths):
             raise ValueError(f"widths must be >= 1, got {list(widths)}")
-        buckets = sorted({_next_pow2(int(w)) for w in (widths or ())})
+        buckets = sorted({_call_width(int(w)) for w in (widths or ())})
         tracer = get_tracer()
         for config in (configs if configs is not None else [self.pipeline]):
             validate_config(config)
@@ -613,7 +624,9 @@ class SolverService:
                     cols.append(b[:, j])
                     owner.append((e, j))
             k = len(cols)
-            k_pad = _next_pow2(k)
+            k_pad = _call_width(k)
+            if k < _MIN_WIDTH:
+                self.metrics.inc("solver.width_floor_groups")
             B = np.zeros((g.n, k_pad), np.float32)
             B[:, :k] = np.stack(cols, axis=1)
             # L is singular with nullspace = constants: only the mean-zero

@@ -170,7 +170,7 @@ def test_warmup_widths_precompile_the_flush_buckets():
     g = mesh2d(10, 10, seed=15)
     svc = SolverService(alpha=0.05)
     h = svc.register(g)
-    sources = svc.warmup(h, widths=[1, 3])     # buckets {1, 4}
+    sources = svc.warmup(h, widths=[1, 3])     # buckets {2, 4}
     assert list(sources.values()) == ["miss"]
     timing = svc.stats()["timing"]
     assert timing["warmup_compile_ms"] > 0
@@ -186,6 +186,22 @@ def test_warmup_widths_precompile_the_flush_buckets():
         assert solve._cache_size() == compiled  # no new XLA compilation
     timing = svc.stats()["timing"]
     assert timing["solve_ms"] > 0
+
+
+def test_warmed_width_one_compiles_nothing_for_a_lone_column():
+    """Width 1 warms the width-2 bucket a lone column runs in, so its
+    first solve compiles nothing new."""
+    g = mesh2d(8, 8, seed=19)
+    svc = SolverService(alpha=0.05, precond="none")
+    h = svc.register(g)
+    svc.warmup(h, widths=[1])
+    key = svc._key(h, svc.pipeline)
+    assert svc._warmed == {(key, 2)}
+    solve = svc._solvers[key]
+    compiled = solve._cache_size()
+    assert compiled == 1
+    assert svc.solve(h, _rhs(g, seed=20)[:, 0]).converged
+    assert solve._cache_size() == compiled
 
 
 def test_rewarm_does_not_inflate_compile_split():

@@ -3,15 +3,17 @@ SolveTicket futures, per-request PipelineConfig overrides and the
 mixed-config scheduler, warmup prefetch, bounded disk cache tier."""
 import os
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import mesh2d
 from repro.core.graph import build_graph
+from repro.obs import get_tracer
 from repro.pipeline import (PipelineConfig, TreeConfig, fegrass_config,
                             pdgrass_config)
 from repro.solver import (GraphHandle, GraphStore, LRUCache, SolveRequest,
-                          SolverService, graph_fingerprint)
+                          SolverService, graph_fingerprint, make_solver)
 from repro.solver import cache as cache_mod
 
 
@@ -333,6 +335,114 @@ def test_padded_batch_columns_are_inert_by_construction():
     assert np.isinf(inner["tol"][3])
     assert inner["maxiter"][3] == 0
     assert inner["iters"][3] == 0
+
+
+# -- width floor: a lone column runs as a width-2 device call ---------------
+
+def _record_calls(svc):
+    """Record every device call ``svc`` makes: the right-hand side's width
+    and the call's solution and per-column iterations, in call order."""
+    calls = []
+    real_solver_for = svc._solver_for
+
+    def solver_for(key, artifacts):
+        fn = real_solver_for(key, artifacts)
+
+        def spy(b, tol=1e-5, maxiter=2000):
+            res = fn(b, tol=tol, maxiter=maxiter)
+            calls.append({"width": int(b.shape[1]), "x": np.asarray(res.x),
+                          "iters": np.asarray(res.iters)})
+            return res
+
+        return spy
+
+    svc._solver_for = solver_for
+    return calls
+
+
+@pytest.fixture(scope="module")
+def lone_column():
+    """One traced single-column request at tol 1e-6, below the f32 pass's
+    floor of 1e-5, so its group makes refinement passes too."""
+    g = mesh2d(12, 12, seed=60)
+    svc = SolverService(alpha=0.05, max_refine=3)
+    h = svc.register(g)
+    calls = _record_calls(svc)
+    b = _rhs(g, k=1, seed=61)[:, 0]
+    tr = get_tracer()
+    was = tr.enabled
+    tr.enable()
+    tr.clear()
+    try:
+        ticket = svc.submit(SolveRequest(graph=h, b=b, tol=1e-6))
+        svc.flush()
+        events = tr.events()
+    finally:
+        tr.clear()
+        tr.enabled = was
+    return svc, h, b, ticket.result(), calls, events
+
+
+def test_lone_column_runs_one_width_two_pass0_call(lone_column):
+    _, _, _, resp, calls, events = lone_column
+    assert resp.converged and resp.refinements >= 1
+    # one pass-0 call and one per refinement pass, every one two wide
+    assert [c["width"] for c in calls] == [2] * (1 + resp.refinements)
+    solves = [e for e in events if e["name"] == "solver.solve"]
+    assert len(solves) == 1
+    assert (solves[0]["args"]["k"], solves[0]["args"]["k_pad"]) == (1, 2)
+    refines = [e for e in events if e["name"] == "solver.refine"]
+    assert len(refines) == resp.refinements
+    assert all((e["args"]["k"], e["args"]["k_pad"]) == (1, 2)
+               for e in refines)
+    group = next(e for e in events if e["name"] == "solver.group")
+    assert (group["args"]["k"], group["args"]["k_pad"]) == (1, 2)
+
+
+def test_lone_column_pad_runs_no_iterations_in_any_pass(lone_column):
+    _, _, _, resp, calls, _ = lone_column
+    assert len(calls) >= 2                     # pass 0 and a refinement
+    assert all(int(c["iters"][1]) == 0 for c in calls)
+    assert np.all(calls[0]["x"][:, 1] == 0)
+    # the response carries the real column alone
+    assert resp.x.shape == (144,) and resp.iters.shape == (1,)
+
+
+def test_lone_column_matches_a_width_one_solve(lone_column):
+    """The widened call runs the same mathematics on the real column: a
+    direct width-1 solve of the same column takes as many iterations and
+    lands on the same solution."""
+    svc, h, b, resp, calls, _ = lone_column
+    _, (idx, val, hier), _ = svc.artifacts(h)
+    direct = make_solver(idx, val, hierarchy=hier, precond=svc.precond)
+    b1 = (b - b.mean())[:, None].astype(np.float32)
+    res = direct(jnp.asarray(b1), tol=jnp.asarray([1e-5], jnp.float32),
+                 maxiter=jnp.asarray([2000], jnp.int32))
+    assert int(np.asarray(res.iters)[0]) == int(calls[0]["iters"][0])
+    x1 = _rebase(np.asarray(res.x)[:, 0])
+    x2 = _rebase(calls[0]["x"][:, 0])
+    np.testing.assert_allclose(x2, x1, rtol=0,
+                               atol=1e-5 * np.abs(x1).max())
+    assert resp.relres[0] <= 1e-6
+
+
+def test_lone_column_counts_one_width_floor_group(lone_column):
+    svc = lone_column[0]
+    assert svc.stats()["metrics"]["solver.width_floor_groups"] == 1
+
+
+def test_three_column_group_pads_to_four_without_the_floor():
+    g = mesh2d(9, 9, seed=62)
+    svc = SolverService(alpha=0.05, precond="none")
+    h = svc.register(g)
+    calls = _record_calls(svc)
+    b = _rhs(g, k=3, seed=63)
+    tickets = [svc.submit(SolveRequest(graph=h, b=b[:, j]))
+               for j in range(3)]
+    out = svc.flush()
+    assert all(out[t].converged for t in tickets)
+    assert calls and all(c["width"] == 4 for c in calls)
+    assert svc.stats()["metrics"].get("solver.width_floor_groups", 0) == 0
 
 
 # -- bounded disk tier -------------------------------------------------------
