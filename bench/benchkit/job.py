@@ -14,9 +14,7 @@ from __future__ import annotations
 import gc
 import time
 
-import numpy as np
-
-from benchkit import devtrace, graphs, reference, traffic
+from benchkit import devtrace, graphs, layout, reference, traffic
 from benchkit.result import Run
 from benchkit.serve import service
 
@@ -25,9 +23,7 @@ def _job(g, b, config, mix, service_kwargs):
     svc = service(config, service_kwargs)
     resp = svc.solve(g, b, tol=float(mix["tol"]), maxiter=int(mix["maxiter"]))
     # the sparsifier the build produced: level 0 of the hierarchy
-    _, (_, _, hier), _ = svc.artifacts(g)
-    lv = hier.levels[0]
-    return resp, np.asarray(lv.idx), np.asarray(lv.val)
+    return resp, layout.laplacian_entries(layout.hierarchy(svc, g).levels[0])
 
 
 def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
@@ -61,10 +57,10 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
             profile.start()
         t_job = time.perf_counter()
         try:
-            resp, idx, val = _job(g, b, config, mix, service_kwargs)
-            jobs.append((b, resp, idx, val, time.perf_counter() - t_job))
+            resp, entries = _job(g, b, config, mix, service_kwargs)
+            jobs.append((b, resp, entries, time.perf_counter() - t_job))
         except Exception as e:     # a failed job: counted, never compared
-            jobs.append((b, e, None, None, time.perf_counter() - t_job))
+            jobs.append((b, e, None, time.perf_counter() - t_job))
         if profile is not None and len(jobs) == 1:
             profile.stop()
     t_end = time.perf_counter()
@@ -80,14 +76,14 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     lap = reference.laplacian(n, src, dst, w)
     worst, failed, iters = 0.0, 0, []
     faults = {}
-    for b, resp, idx, val, _ in jobs:
+    for b, resp, entries, _ in jobs:
         if isinstance(resp, Exception):
             failed += 1
             continue
         worst = max(worst, float(reference.relres(lap, b, resp.x).max()))
         iters.extend(int(k) for k in resp.iters)
         for k, v in reference.sparsifier_faults(
-                n, src, dst, w, idx, val,
+                n, src, dst, w, *entries,
                 float(config["solver"]["alpha"])).items():
             faults[k] = max(faults.get(k, 0), v)
 
@@ -99,7 +95,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         out.compare(k, v, 0)
     out.notes += [
         f"jobs {len(jobs)} in {t_end - t0:.3f} s (window {seconds} s): "
-        + " ".join(f"{j[4]:.3f}" for j in jobs),
+        + " ".join(f"{j[3]:.3f}" for j in jobs),
         f"iterations per column min {min(iters, default=0)} max "
         f"{max(iters, default=0)}",
     ]

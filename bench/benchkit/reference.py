@@ -10,9 +10,11 @@ Laplacian is assembled by scipy from the benchmark's own edge arrays.
 * A sparsifier (pdGRASS's output: a spanning tree plus recovered off-tree
   edges) is correct when every edge of it is an edge of the graph with the
   graph's own weight, it connects every vertex, and it keeps at most
-  ``n - 1 + ceil(alpha * n)`` edges.  It is read from the ELL slabs of its
-  Laplacian (``idx``/``val`` rows: ``-w`` per neighbour, the weighted
-  degree on the diagonal, zero-valued padding), the form the solve uses.
+  ``n - 1 + ceil(alpha * n)`` edges.  It is read from the stored entries
+  of its Laplacian as ``(rows, cols, vals)`` triplets: ``-w`` per
+  neighbour, the weighted degree on the diagonal, in any order and split
+  however the program stores them; entries of one position add up, as in
+  any sparse matrix.
 """
 from __future__ import annotations
 
@@ -43,40 +45,42 @@ def relres(lap: sp.csr_matrix, b, x) -> np.ndarray:
     return np.linalg.norm(b - lap @ x, axis=0) / np.linalg.norm(b, axis=0)
 
 
-def sparsifier_edges(idx, val):
+def sparsifier_edges(rows, cols, vals):
     """``(src, dst, w)`` with ``src < dst`` of the off-diagonal entries of
-    an ELL Laplacian slab, and whether the slab is symmetric."""
-    idx = np.asarray(idx)
-    val = np.asarray(val)
-    rows = np.broadcast_to(np.arange(idx.shape[0])[:, None], idx.shape)
-    off = (idx != rows) & (val != 0)
-    r, c, v = rows[off], idx[off], -val[off]
-    upper = r < c
-    fwd = {(int(a), int(b)): float(x)
-           for a, b, x in zip(r[upper], c[upper], v[upper])}
-    bwd = {(int(b), int(a)): float(x)
-           for a, b, x in zip(r[~upper], c[~upper], v[~upper])}
-    symmetric = fwd == bwd
-    keys = np.asarray(sorted(fwd), np.int64).reshape(-1, 2)
-    w = np.asarray([fwd[tuple(k)] for k in keys.tolist()], np.float64)
-    return keys[:, 0], keys[:, 1], w, symmetric
+    a Laplacian given as ``(rows, cols, vals)`` triplets, and whether it is
+    symmetric."""
+    rows = np.asarray(rows, np.int64)
+    cols = np.asarray(cols, np.int64)
+    vals = np.asarray(vals, np.float64)
+    off = rows != cols
+    size = int(max(rows.max(initial=-1), cols.max(initial=-1))) + 1
+    a = sp.csr_matrix((-vals[off], (rows[off], cols[off])),
+                      shape=(size, size))
+    a.eliminate_zeros()
+    symmetric = (a != a.T).nnz == 0
+    upper = sp.triu(a, k=1).tocoo()
+    return (upper.row.astype(np.int64), upper.col.astype(np.int64),
+            upper.data, symmetric)
 
 
-def sparsifier_faults(n: int, src, dst, weight, idx, val,
+def sparsifier_faults(n: int, src, dst, weight, rows, cols, vals,
                       alpha: float) -> dict:
-    """Counts of each way the sparsifier in ``idx``/``val`` breaks the
-    guarantees; all zero when it is correct."""
-    s, d, w, symmetric = sparsifier_edges(idx, val)
-    graph_w = {(int(a), int(b)): float(x)
-               for a, b, x in zip(src, dst, np.asarray(weight, np.float32))}
-    not_in_graph = sum(
-        1 for a, b, x in zip(s.tolist(), d.tolist(), w.tolist())
-        if graph_w.get((a, b)) != float(np.float32(x)))
-    adj = sp.coo_matrix((np.ones(len(s)), (s, d)), shape=(n, n))
+    """Counts of each way the sparsifier whose Laplacian has the entries
+    ``(rows, cols, vals)`` breaks the guarantees; all zero when it is
+    correct."""
+    s, d, w, symmetric = sparsifier_edges(rows, cols, vals)
+    key = np.asarray(src, np.int64) * n + np.asarray(dst, np.int64)
+    order = np.argsort(key)
+    key, graph_w = key[order], np.asarray(weight, np.float32)[order]
+    at = np.minimum(np.searchsorted(key, s * n + d), len(key) - 1)
+    in_graph = ((d < n) & (key[at] == s * n + d)
+                & (graph_w[at] == w.astype(np.float32)))
+    size = max(n, int(d.max(initial=-1)) + 1)
+    adj = sp.coo_matrix((np.ones(len(s)), (s, d)), shape=(size, size))
     components, _ = connected_components(adj, directed=False)
     budget = n - 1 + math.ceil(alpha * n)
     return {
-        "sparsifier_edges_not_in_graph": int(not_in_graph),
+        "sparsifier_edges_not_in_graph": int((~in_graph).sum()),
         "sparsifier_asymmetric_rows": int(not symmetric),
         "sparsifier_extra_components": int(components - 1),
         "sparsifier_edges_over_budget": int(max(0, len(s) - budget)),

@@ -15,7 +15,7 @@ import time
 
 import numpy as np
 
-from benchkit import devtrace, graphs, reference, stats, traffic
+from benchkit import devtrace, graphs, layout, reference, stats, traffic
 from benchkit.result import Run
 
 RESULT_WAIT_S = 60.0
@@ -155,9 +155,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         "peak_bytes_in_use")
     cycles = daemon.stats()["daemon"]
     daemon.close()
-    _, (_, _, hier), _ = svc.artifacts(handle)
-    levels = [(int(lv.n), int(lv.idx.shape[1])) for lv in hier.levels]
-    del hier
+    levels = [(int(lv.n), layout.slab_shapes(lv))
+              for lv in layout.hierarchy(svc, handle).levels]
     calls = recorder.calls if recorder is not None else []
     del svc, daemon, handle, recorder
     gc.collect()

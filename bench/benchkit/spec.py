@@ -68,11 +68,12 @@ def layer_reader(metric: str, bench_dir: str = BENCH_DIR
     events of the window (``spans``), per-column iteration counts from the
     responses (``iters``), the window's change of the daemon's queue-wait
     histogram (``queue_wait``: sum, count), the recorded device solve calls
-    (``calls``), the hierarchy's ``(n, ell_width)`` per level
-    (``levels``), the plain device trace (``trace_plain``) and its summary
-    (``trace``), the job count (``jobs``) and the chip's peaks
-    (``peaks``).  A reader that finds nothing to read returns ``None``
-    and its metric is left out of the result.
+    (``calls``), per level of the hierarchy its vertex count and ELL
+    blocks, ``(n, ((rows, width), ...))`` (``levels``), the plain device
+    trace (``trace_plain``) and its summary (``trace``), the job count
+    (``jobs``) and the chip's peaks (``peaks``).  A reader that finds
+    nothing to read returns ``None`` and its metric is left out of the
+    result.
     """
     path = os.path.join(bench_dir, "layer_metrics", f"{metric}.py")
     mod_name = "layer_metric_" + metric.replace(".", "_").replace("-", "_")

@@ -11,14 +11,14 @@ def read(ctx):
                             ctx.get("levels"))
     if not trace or not calls or not levels:
         return None
-    n0, width0 = levels[0]
+    n0, blocks0 = levels[0]
     moved, dev_ns = 0, 0.0
     for name, start, dur in devtrace.host_spans(trace, "bench.solve_call."):
         call = calls[int(name.rsplit(".", 1)[1])]
         t = devtrace.scoped_time_ns(trace, "vcycle.L0.", start, start + dur)
         if t <= 0:
             continue
-        moved += (call["loops"] + 1) * vcycle_level_bytes(n0, width0,
+        moved += (call["loops"] + 1) * vcycle_level_bytes(n0, blocks0,
                                                           call["k"])
         dev_ns += t
     if dev_ns <= 0:

@@ -37,22 +37,8 @@ CONTROL = {"max_refine": 0}
 SERVE = dict(rate_hz=8.0, widths=[[1, 0.5], [2, 0.5]], max_batch_columns=2)
 
 
-def job_cell():
-    """The batch-job cell, built from its own files: its driver and mix are
-    part of the harness, its cell is not yet in ``BENCHMARK.json``."""
-    return spec.Cell(
-        name="social_ba.job", chips=1,
-        config=spec.load_json(os.path.join(BENCH, "configs",
-                                           "social_ba.json")),
-        traffic=spec.load_json(os.path.join(BENCH, "traffic", "job.json")),
-        end_to_end=[{"name": "setup_s", "unit": "s"},
-                    {"name": "job_s", "unit": "s"}],
-        per_layer=[])
-
-
 def small(cell_name, **traffic):
-    cell = (job_cell() if cell_name == "social_ba.job"
-            else spec.resolve(cell_name, ROOT))
+    cell = spec.resolve(cell_name, ROOT)
     g = cell.config["graph"]
     g["side" if g["family"] == "mesh2d" else "n"] = \
         64 if g["family"] == "mesh2d" else 1024

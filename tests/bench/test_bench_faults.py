@@ -1,7 +1,6 @@
 """A run of each cell, on the CPU at a tiny size, with the timed path
 broken underneath: every fault the cells can have must read as
 ``correct: false``, and the unbroken run as ``correct: true``."""
-import dataclasses
 import os
 import sys
 import time
@@ -35,22 +34,8 @@ bench_run = _load_run()
 PEAKS = {"hbm_bytes_per_s": 819e9}
 
 
-def job_cell():
-    """The batch-job cell, built from its own files: its driver and mix are
-    part of the harness, its cell is not yet in ``BENCHMARK.json``."""
-    return spec.Cell(
-        name="social_ba.job", chips=1,
-        config=spec.load_json(os.path.join(BENCH, "configs",
-                                           "social_ba.json")),
-        traffic=spec.load_json(os.path.join(BENCH, "traffic", "job.json")),
-        end_to_end=[{"name": "setup_s", "unit": "s"},
-                    {"name": "job_s", "unit": "s"}],
-        per_layer=[])
-
-
 def tiny(cell_name, **traffic):
-    cell = (job_cell() if cell_name == "social_ba.job"
-            else spec.resolve(cell_name, ROOT))
+    cell = spec.resolve(cell_name, ROOT)
     g = cell.config["graph"]
     if g["family"] == "mesh2d":
         g["side"] = 20
@@ -146,22 +131,19 @@ def test_altered_answer_is_not_correct(tmp_path, monkeypatch, cell_name,
 
 
 def test_altered_sparsifier_is_not_correct(tmp_path, monkeypatch):
-    """A sparsifier edge whose weight differs from the graph's."""
-    import repro.solver.service as service
+    """A sparsifier edge whose weight differs from the graph's: one
+    off-diagonal entry of level 0's Laplacian, as the harness reads it
+    through ``layout`` (whatever the program's slab layout), scaled."""
+    from benchkit import layout
 
-    real = service.build_hierarchy
+    real = layout.laplacian_entries
 
-    def build_hierarchy(*a, **k):
-        hier = real(*a, **k)
-        lv = hier.levels[0]
-        val = np.array(lv.val)
-        row = int(np.flatnonzero((val < 0).any(axis=1))[0])
-        col = int(np.flatnonzero(val[row] < 0)[0])
-        val[row, col] *= 1.25
-        return dataclasses.replace(hier, levels=(dataclasses.replace(
-            lv, val=jnp.asarray(val)),) + hier.levels[1:])
+    def laplacian_entries(level):
+        rows, cols, vals = (np.array(a) for a in real(level))
+        vals[np.flatnonzero(rows < cols)[0]] *= 1.25
+        return rows, cols, vals
 
-    monkeypatch.setattr(service, "build_hierarchy", build_hierarchy)
+    monkeypatch.setattr(layout, "laplacian_entries", laplacian_entries)
     run = measure(tiny("social_ba.job"), tmp_path)
     assert not run.correct
     assert run.compared["sparsifier_edges_not_in_graph"][0] >= 1

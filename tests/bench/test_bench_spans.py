@@ -70,6 +70,35 @@ def test_tail_queue_ms_is_the_mean_wait_at_the_tail():
     assert read("tail_queue_ms.serve", _window()) == pytest.approx(52.0)
 
 
+def _job_window():
+    """Two jobs: each a group that builds its artifacts (sparsify, then
+    contract, per level) and then solves."""
+    spans = []
+    for t0 in (0, 5000):
+        spans += [
+            _span("solver.group", t0, 4000, k=8, k_pad=8),
+            _span("solver.artifacts", t0 + 10, 3000, depth=1),
+            _span("pipeline.prepare", t0 + 20, 500, depth=4),
+            _span("pipeline.tree", t0 + 30, 100, depth=5),
+            _span("pipeline.recovery", t0 + 600, 300, depth=4),
+            _span("hierarchy.contract", t0 + 1000, 200, depth=3),
+            _span("pipeline.prepare", t0 + 1300, 250, depth=4),
+            _span("pipeline.recovery", t0 + 1600, 150, depth=4),
+            _span("hierarchy.contract", t0 + 1800, 100, depth=3),
+            _span("solver.solve", t0 + 3100, 700, depth=1, loops=90),
+        ]
+    return spans
+
+
+@pytest.mark.parametrize("metric,seconds", [
+    ("prepare_s.job", 0.75), ("recovery_s.job", 0.45),
+    ("contract_s.job", 0.3), ("solve_s.job", 1.0)])
+def test_job_readers_give_seconds_per_job(metric, seconds):
+    ctx = {"spans": _job_window(), "jobs": 2}
+    assert spec.layer_reader(metric)(ctx) == pytest.approx(seconds)
+    assert spec.layer_reader(metric)({"spans": [], "jobs": 2}) is None
+
+
 NEW = ["device_call_iter_ms.serve", "group_host_ms.serve",
        "tail_queue_ms.serve"]
 
@@ -120,3 +149,20 @@ def test_device_call_spans_match_the_recorded_calls(tmp_path):
                                                             c["loops"])
     for metric in NEW:
         assert metrics[metric]["value"] > 0
+
+
+def test_traced_job_run_reads_every_program_reader(tmp_path):
+    """A traced job run at a CPU size: every reader of the job cell that
+    reads the program's spans or responses finds something; the device's
+    idle share needs a TPU's trace and reads nothing here."""
+    cell = spec.resolve("social_ba.job", ROOT)
+    cell.config["graph"]["n"] = 300
+    run, metrics, _ = _load_run().measure(
+        cell, 2**31 + 17, 1.0, True, {"hbm_bytes_per_s": 819e9},
+        cache=str(tmp_path), t_start=time.perf_counter())
+    assert run.correct and run.failed == 0
+    names = {m["name"] for m in cell.per_layer}
+    assert names == {"recovery_s.job", "prepare_s.job", "contract_s.job",
+                     "solve_s.job", "pcg_iters.job", "idle_share.job"}
+    assert set(metrics) == names - {"idle_share.job"}
+    assert all(m["value"] > 0 for m in metrics.values())

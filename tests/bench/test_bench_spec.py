@@ -148,20 +148,15 @@ def test_reference_accepts_a_solution_and_rejects_a_perturbed_one(small_mesh):
     assert np.all(reference.relres(lap, b, x[:, :2]) == np.inf)
 
 
-def _ell(n, s, d, wt):
-    """ELL Laplacian slab in the program's layout, built here."""
-    deg = np.bincount(np.concatenate([s, d]), minlength=n)
-    width = deg.max() + 1
-    idx = np.tile(np.arange(n)[:, None], (1, width))
-    val = np.zeros((n, width), np.float32)
-    fill = np.zeros(n, np.int64)
-    for a, b, x in zip(s, d, wt):
-        for u, v in ((a, b), (b, a)):
-            idx[u, fill[u]], val[u, fill[u]] = v, -x
-            fill[u] += 1
-    val[np.arange(n), fill] = np.bincount(
-        np.concatenate([s, d]), np.concatenate([wt, wt]), minlength=n)
-    return idx, val
+def _entries(n, s, d, wt):
+    """``(rows, cols, vals)`` of the Laplacian of the edges ``s, d, wt``:
+    ``-w`` both ways and the weighted degree on the diagonal."""
+    deg = np.bincount(np.concatenate([s, d]), np.concatenate([wt, wt]),
+                      minlength=n)
+    rows = np.concatenate([s, d, np.arange(n)])
+    cols = np.concatenate([d, s, np.arange(n)])
+    vals = np.concatenate([-wt, -wt, deg]).astype(np.float32)
+    return rows, cols, vals
 
 
 def test_sparsifier_check_passes_a_spanning_tree_and_catches_faults(
@@ -175,19 +170,19 @@ def test_sparsifier_check_passes_a_spanning_tree_and_catches_faults(
     keep = {(min(a, b), max(a, b)) for a, b in zip(t.row, t.col)}
     mask = np.array([(a, b) in keep for a, b in zip(src, dst)])
     s, d, wt = src[mask], dst[mask], w[mask]
-    ok = reference.sparsifier_faults(n, src, dst, w, *_ell(n, s, d, wt),
-                                     alpha=0.05)
+    ok = reference.sparsifier_faults(n, src, dst, w,
+                                     *_entries(n, s, d, wt), alpha=0.05)
     assert set(ok.values()) == {0}
     wrong_w = wt.copy()
     wrong_w[0] *= 1.5
     assert reference.sparsifier_faults(
-        n, src, dst, w, *_ell(n, s, d, wrong_w),
+        n, src, dst, w, *_entries(n, s, d, wrong_w),
         alpha=0.05)["sparsifier_edges_not_in_graph"] == 1
-    cut = reference.sparsifier_faults(n, src, dst, w,
-                                      *_ell(n, s[1:], d[1:], wt[1:]), 0.05)
+    cut = reference.sparsifier_faults(
+        n, src, dst, w, *_entries(n, s[1:], d[1:], wt[1:]), 0.05)
     assert cut["sparsifier_extra_components"] == 1
     full = reference.sparsifier_faults(n, src, dst, w,
-                                       *_ell(n, src, dst, w), 0.05)
+                                       *_entries(n, src, dst, w), 0.05)
     assert full["sparsifier_edges_over_budget"] > 0
 
 
@@ -199,10 +194,10 @@ def test_peaks_table_knows_the_v5e_and_refuses_others():
 
 
 def test_vcycle_byte_model_counts_four_contractions():
-    one = vcycle_level_bytes(100, 7, 1)
+    one = vcycle_level_bytes(100, ((100, 7),), 1)
     assert one == 4 * (100 * 7 * 8 + 100 * 7 * 4 + 100 * 4) + \
         2 * (100 * 4 + 2 * 100 * 4)
-    assert vcycle_level_bytes(100, 7, 16) > one
+    assert vcycle_level_bytes(100, ((100, 7),), 16) > one
 
 
 RECORDED = os.path.join(BENCH, "testdata", "trace_small.json")
